@@ -1,0 +1,6 @@
+# The benchmark's own tests run on the CPU:  python -m pytest bench -q
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
