@@ -83,42 +83,49 @@ def reassembly_plan(ev_hi, ev_lo, daq, seg_index, n_segs, valid, *,
       n_groups   int32 scalar
     """
     n = ev_hi.shape[0]
-    arrival = jnp.arange(n, dtype=jnp.int32)
-    inval = (~valid).astype(jnp.uint32)  # invalid rows sort last
-    s_inval, s_hi, s_lo, s_daq, s_seg, s_arr, s_nsegs = jax.lax.sort(
-        (inval, ev_hi.astype(jnp.uint32), ev_lo.astype(jnp.uint32),
-         daq.astype(jnp.uint32), seg_index.astype(jnp.uint32),
-         arrival, n_segs.astype(jnp.int32)),
-        num_keys=6,
-    )
-    s_valid = (s_inval == 0).astype(jnp.uint32)
-    if backend == "pallas":
-        from repro.kernels import reassembly as _k
+    # One named scope per step, in program order: the scopes name the
+    # compiled instructions (their ``op_name``) and nothing else, so the
+    # benchmark's trace reader can give each step its device time.
+    with jax.named_scope("reassembly.sort"):
+        arrival = jnp.arange(n, dtype=jnp.int32)
+        inval = (~valid).astype(jnp.uint32)  # invalid rows sort last
+        s_inval, s_hi, s_lo, s_daq, s_seg, s_arr, s_nsegs = jax.lax.sort(
+            (inval, ev_hi.astype(jnp.uint32), ev_lo.astype(jnp.uint32),
+             daq.astype(jnp.uint32), seg_index.astype(jnp.uint32),
+             arrival, n_segs.astype(jnp.int32)),
+            num_keys=6,
+        )
+    with jax.named_scope("reassembly.groups"):
+        s_valid = (s_inval == 0).astype(jnp.uint32)
+        if backend == "pallas":
+            from repro.kernels import reassembly as _k
 
-        new_group, dup = _k.seg_masks(s_valid, s_hi, s_lo, s_daq, s_seg,
-                                      interpret=interpret)
-    else:
-        from repro.kernels import ref as _ref
+            new_group, dup = _k.seg_masks(s_valid, s_hi, s_lo, s_daq, s_seg,
+                                          interpret=interpret)
+        else:
+            from repro.kernels import ref as _ref
 
-        new_group, dup = _ref.seg_masks_ref(s_valid, s_hi, s_lo, s_daq, s_seg)
-    ok = s_valid > 0
-    unique = ok & (dup == 0)
-    gid = jnp.cumsum(new_group) - 1  # dense group id along sorted order
-    gid_c = jnp.clip(gid, 0, n - 1)
-    # Per-group unique-segment counts + expected size, one scatter each
-    # (padding/duplicate rows are routed to a spill slot at index n).
-    counts = jnp.zeros((n + 1,), jnp.int32).at[
-        jnp.where(unique, gid_c, n)].add(1)
-    # Expected size = the group's *first* row's n_segs (same definition as
-    # the host plan; only group-start rows contribute to the scatter).
-    gsegs = jnp.zeros((n + 1,), jnp.int32).at[
-        jnp.where(ok & (new_group > 0), gid_c, n)].max(s_nsegs)
-    complete_g = (counts[:n] > 0) & (counts[:n] == gsegs[:n])
-    complete = ok & complete_g[gid_c]
+            new_group, dup = _ref.seg_masks_ref(s_valid, s_hi, s_lo, s_daq, s_seg)
+        ok = s_valid > 0
+        unique = ok & (dup == 0)
+        gid = jnp.cumsum(new_group) - 1  # dense group id along sorted order
+        gid_c = jnp.clip(gid, 0, n - 1)
+    with jax.named_scope("reassembly.count"):
+        # Per-group unique-segment counts + expected size, one scatter each
+        # (padding/duplicate rows are routed to a spill slot at index n).
+        counts = jnp.zeros((n + 1,), jnp.int32).at[
+            jnp.where(unique, gid_c, n)].add(1)
+        # Expected size = the group's *first* row's n_segs (same definition as
+        # the host plan; only group-start rows contribute to the scatter).
+        gsegs = jnp.zeros((n + 1,), jnp.int32).at[
+            jnp.where(ok & (new_group > 0), gid_c, n)].max(s_nsegs)
+    with jax.named_scope("reassembly.complete"):
+        complete_g = (counts[:n] > 0) & (counts[:n] == gsegs[:n])
+        complete = ok & complete_g[gid_c]
+        n_groups = jnp.sum(new_group)
     return {
         "perm": s_arr, "new_group": new_group, "dup": dup, "unique": unique,
-        "complete": complete, "group_id": gid_c,
-        "n_groups": jnp.sum(new_group),
+        "complete": complete, "group_id": gid_c, "n_groups": n_groups,
     }
 
 

@@ -1,7 +1,9 @@
 """Batched ingest path: segmentation parity, WAN batch semantics, sort-based
 reassembly (all backends), timeout/loss accounting, telemetry feedback, and
 the closed-loop driver (DESIGN.md §Ingest)."""
+import functools
 import os
+import re
 import subprocess
 import sys
 
@@ -276,6 +278,57 @@ class TestBatchReassembler:
         done = ra.push_batch(segment_bundles(bundles))
         assert len(done) == 1
         assert np.array_equal(done[0], bundles[0].payload)
+
+
+REASSEMBLY_SCOPES = ("reassembly.sort", "reassembly.groups",
+                     "reassembly.count", "reassembly.complete")
+
+
+def _computations(text):
+    """A compiled module's computations, without ``metadata={...}`` or the
+    debug tables (file names, source lines) that only metadata refers to."""
+    body = "\n".join(line for line in text.splitlines()
+                     if line.startswith(("%", "ENTRY", " ", "}")))
+    return re.sub(r", metadata=\{[^{}]*\}", "", body)
+
+
+@functools.lru_cache(maxsize=None)
+def _compiled_plans():
+    """The compiled text of the jnp ``reassembly_plan`` at a small N, with
+    its named scopes and with ``jax.named_scope`` made a no-op."""
+    import contextlib
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+
+    n = 96
+    cols = [jnp.zeros((n,), jnp.uint32)] * 5 + [jnp.ones((n,), bool)]
+    scoped = reassembly_plan.lower(*cols, backend="jnp").compile().as_text()
+    raw = reassembly_plan.__wrapped__
+
+    # a fresh function (same name and signature), so JAX traces it anew
+    @functools.wraps(raw)
+    def unscoped(*a, **k):
+        return raw(*a, **k)
+
+    with mock.patch.object(jax, "named_scope", lambda name: contextlib.nullcontext()):
+        plain = jax.jit(unscoped, static_argnames=("backend", "interpret")).lower(
+            *cols, backend="jnp").compile().as_text()
+    return scoped, plain
+
+
+@pytest.mark.parametrize("scope", REASSEMBLY_SCOPES)
+def test_reassembly_scope_names_ops_only(scope):
+    """Each step of the reassembly program carries its named scope in the
+    compiled ops' ``op_name``; without the scopes the compiled program is
+    the same, instruction for instruction."""
+    scoped, plain = _compiled_plans()
+    op_names = re.findall(r'op_name="([^"]*)"', scoped)
+    assert any(f"/{scope}/" in name for name in op_names)
+    assert not any(f"/{scope}/" in name
+                   for name in re.findall(r'op_name="([^"]*)"', plain))
+    assert _computations(scoped) == _computations(plain)
 
 
 class TestTelemetryFeedback:
