@@ -6,9 +6,12 @@ batched path mirrors PR 1's dispatch algorithm instead: the whole arrival
 window is key-sorted on ``(event_hi, event_lo, daq_id, seg_index, arrival)``
 with one multi-operand ``lax.sort``; group boundaries and duplicates fall out
 of a previous-row comparison on the sorted columns (jnp reference or the
-Pallas kernel ``kernels/reassembly.seg_masks``); per-group unique-segment
-counts come from one segment-scatter, and a group is complete iff its unique
-count equals its ``n_segs``. O(N log N) work, no per-packet host loop.
+Pallas kernel ``kernels/reassembly.seg_masks``). Each group is then a
+contiguous run of sorted rows, so per-group unique-segment counts and the
+broadcast of each group's verdict back to its rows are cumulative scans
+(``cumsum``, a reverse ``cummin``, a ``cummax``) with no scatter or gather; a
+group is complete iff its unique count equals its ``n_segs``. O(N log N)
+work, no per-packet host loop.
 
 ``BatchReassembler`` carries incomplete groups across windows (loss shows up
 as pending buffers), ages them, and times them out after
@@ -111,17 +114,25 @@ def reassembly_plan(ev_hi, ev_lo, daq, seg_index, n_segs, valid, *,
         gid = jnp.cumsum(new_group) - 1  # dense group id along sorted order
         gid_c = jnp.clip(gid, 0, n - 1)
     with jax.named_scope("reassembly.count"):
-        # Per-group unique-segment counts + expected size, one scatter each
-        # (padding/duplicate rows are routed to a spill slot at index n).
-        counts = jnp.zeros((n + 1,), jnp.int32).at[
-            jnp.where(unique, gid_c, n)].add(1)
-        # Expected size = the group's *first* row's n_segs (same definition as
-        # the host plan; only group-start rows contribute to the scatter).
-        gsegs = jnp.zeros((n + 1,), jnp.int32).at[
-            jnp.where(ok & (new_group > 0), gid_c, n)].max(s_nsegs)
+        # A group is a contiguous run of sorted rows and the running count of
+        # unique rows ``cu`` never decreases, so a reverse running minimum of
+        # ``cu`` over group-end rows gives each row its group's final ``cu``.
+        cu = jnp.cumsum(unique, dtype=jnp.int32)
+        next_open = jnp.concatenate(
+            [(new_group[1:] > 0) | ~ok[1:], jnp.ones((1,), bool)])
+        cu_end = jax.lax.cummin(
+            jnp.where(ok & next_open, cu, jnp.iinfo(jnp.int32).max),
+            reverse=True)
     with jax.named_scope("reassembly.complete"):
-        complete_g = (counts[:n] > 0) & (counts[:n] == gsegs[:n])
-        complete = ok & complete_g[gid_c]
+        # A group's first row is unique, so the group's unique count there is
+        # ``cu_end - cu + 1``, and its own ``n_segs`` is the expected size.
+        start = new_group > 0
+        flag = (start & (cu_end - cu + 1 == s_nsegs)).astype(jnp.int32)
+        # 2 * row + flag rises with the row, so a running maximum carries the
+        # verdict of each row's latest group start forward to it.
+        row = jnp.arange(n, dtype=jnp.int32)
+        filled = jax.lax.cummax(jnp.where(start, 2 * row + flag, 0))
+        complete = ok & ((filled & 1) == 1)
         n_groups = jnp.sum(new_group)
     return {
         "perm": s_arr, "new_group": new_group, "dup": dup, "unique": unique,

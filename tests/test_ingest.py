@@ -202,6 +202,8 @@ class TestBatchReassembler:
             assert int(dev["n_groups"]) == host["n_groups"]
             dperm = np.asarray(dev["perm"])[:n]
             assert np.array_equal(dperm, host["perm"])
+            assert np.array_equal(np.asarray(dev["group_id"])[:n],
+                                  host["group_id"])
             for k in ("new_group", "dup", "unique", "complete"):
                 assert np.array_equal(
                     np.asarray(dev[k])[:n].astype(bool),
@@ -329,6 +331,103 @@ def test_reassembly_scope_names_ops_only(scope):
     assert not any(f"/{scope}/" in name
                    for name in re.findall(r'op_name="([^"]*)"', plain))
     assert _computations(scoped) == _computations(plain)
+
+
+def _groups(sizes, n_segs=None, seed=0):
+    """Columns of consecutive (event, daq) groups of ``sizes`` segments,
+    segment indices 0.. in each, expected size = group size unless given,
+    arrival order shuffled."""
+    ev = np.repeat(np.arange(len(sizes), dtype=np.uint64) * 7 + 3, sizes)
+    daq = np.repeat(np.arange(len(sizes), dtype=np.int32) % 3, sizes)
+    seg = np.concatenate([np.arange(k, dtype=np.int32) for k in sizes])
+    nsg = np.repeat(np.asarray(sizes if n_segs is None else n_segs,
+                               np.int32), sizes)
+    order = np.random.default_rng(seed).permutation(len(ev))
+    return ev[order], daq[order], seg[order], nsg[order]
+
+
+def _first_row_decides():
+    """Expected sizes differ inside a group; only the first sorted row's
+    (lowest segment, earliest arrival) counts."""
+    ev = np.asarray([5, 5, 5, 9, 9, 9, 9], np.uint64)
+    daq = np.zeros((7,), np.int32)
+    seg = np.asarray([2, 0, 1, 1, 2, 0, 0], np.int32)
+    nsg = np.asarray([7, 3, 5, 3, 3, 4, 3], np.int32)
+    return ev, daq, seg, nsg  # group 5 complete (3 of 3), group 9 not (3 of 4)
+
+
+def _one_row(n_segs):
+    return (np.asarray([11], np.uint64), np.asarray([2], np.int32),
+            np.asarray([0], np.int32), np.asarray([n_segs], np.int32))
+
+
+_empty = np.zeros((0,), np.int32)
+
+# name -> (columns of the valid rows, padded window size)
+PLAN_CASES = {
+    # groups of 1000 and 100 rows: the second crosses the 1024-row block
+    # boundary of ``seg_masks``; the third is one short of its n_segs
+    "across_block": (_groups([1000, 100, 40, 60], n_segs=[1000, 100, 41, 60]),
+                     2048),
+    "one_group_whole_window": (_groups([1024]), 1024),
+    "one_group_missing_one": (_groups([1024], n_segs=[1025]), 1024),
+    "ends_on_last_row": (_groups([3, 5, 120, 128]), 256),
+    "all_padding": ((_empty.astype(np.uint64), _empty, _empty, _empty), 8),
+    "all_duplicates": ((np.full((64,), 5, np.uint64), np.ones((64,), np.int32),
+                        np.zeros((64,), np.int32), np.ones((64,), np.int32)),
+                       64),
+    "n_segs_differ_in_group": (_first_row_decides(), 16),
+    "n1_complete": (_one_row(1), 1),
+    "n1_incomplete": (_one_row(2), 1),
+}
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+@pytest.mark.parametrize("case", list(PLAN_CASES))
+def test_reassembly_plan_matches_host(case, backend):
+    """The device plan equals the host plan on every output, valid rows
+    first in the padded window; padding rows are never unique or complete."""
+    import jax.numpy as jnp
+
+    (ev, daq, seg, nsg), n_pad = PLAN_CASES[case]
+    n = len(ev)
+    hi, lo = split64(ev)
+    host = reassembly_plan_np(hi, lo, daq, seg, nsg)
+
+    def pad(x, dtype):
+        out = np.zeros((n_pad,), dtype)
+        out[:n] = x
+        return jnp.asarray(out)
+
+    dev = reassembly_plan(
+        pad(hi, np.uint32), pad(lo, np.uint32), pad(daq, np.int32),
+        pad(seg, np.int32), pad(nsg, np.int32), pad(np.ones(n, bool), bool),
+        backend=backend, interpret=True)
+    dev = {k: np.asarray(v) for k, v in dev.items()}
+    assert int(dev["n_groups"]) == host["n_groups"]
+    assert np.array_equal(dev["perm"][:n], host["perm"])
+    assert np.array_equal(dev["group_id"][:n], host["group_id"])
+    for k in ("new_group", "dup", "unique", "complete"):
+        assert np.array_equal(dev[k][:n].astype(bool), host[k]), k
+        assert not dev[k][n:].any(), k
+    if n:
+        # padding rows sort last and keep the last group's id
+        assert (dev["group_id"][n:] == host["n_groups"] - 1).all()
+
+
+def test_reassembly_plan_has_no_scatter_or_gather():
+    """At the benchmark's 2^17-row window the compiled jnp plan counts,
+    fills and broadcasts by scans alone: no scatter or gather instruction."""
+    import jax
+    import jax.numpy as jnp
+
+    n = 1 << 17
+    cols = ([jax.ShapeDtypeStruct((n,), jnp.uint32)] * 5
+            + [jax.ShapeDtypeStruct((n,), jnp.bool_)])
+    text = reassembly_plan.lower(*cols, backend="jnp").compile().as_text()
+    ops = re.findall(r"\b([a-z][a-z-]*)\(", _computations(text))
+    assert "sort" in ops and "reduce-window" in ops
+    assert not {"scatter", "gather"} & set(ops)
 
 
 class TestTelemetryFeedback:
