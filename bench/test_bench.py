@@ -86,3 +86,17 @@ def test_result_line_keys(capsys):
     assert list(line)[-1] == "checks"
     assert {"correct", "attempted", "failed", "metrics", "device"} <= set(line)
     assert out.err.strip().splitlines()[-1].startswith("check route_mismatch 0 limit 0")
+
+
+def test_benchmark_json_is_whole():
+    spec = harness.load_json(harness.ROOT, "BENCHMARK.json")
+    cells = {w["name"] for w in spec["workloads"]}
+    metrics = spec["end_to_end"] + spec["per_layer"]
+    for m in spec["per_layer"]:
+        own, base = (os.path.join(harness.BENCH, "metrics", n + ".py")
+                     for n in (m["name"], harness.base(m["name"])))
+        assert os.path.exists(own) or os.path.exists(base), m["name"]
+    for m in metrics:
+        assert set(m.get("workloads", cells)) <= cells, m["name"]
+    for m in spec["end_to_end"]:
+        assert 0 < m["bound"] < 1, m["name"]

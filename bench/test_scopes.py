@@ -7,6 +7,7 @@ from types import SimpleNamespace as NS
 
 import pytest
 
+import harness
 import scopes
 import tracereduce
 from test_tracereduce import RECORDED, chip, ev
@@ -135,6 +136,40 @@ def test_reader_without_the_scope_reads_none(tmp_path, monkeypatch):
     assert scopes.read(ctx, "bench_reassembly", "reassembly.count") is None
     monkeypatch.setattr(scopes, "TRACES", str(tmp_path / "none"))
     assert scopes.read(ctx, "bench_reassembly", "reassembly.count") is None
+
+
+def scans(monkeypatch, names, planes=None, n_chips=2):
+    """``reassembly_scans_ms`` read from the two-chip trace, with
+    ``names`` as the live executables' op names."""
+    planes = planes or traced()
+    monkeypatch.setattr(scopes, "newest_trace", lambda root: "traced")
+    monkeypatch.setattr(scopes, "_trace_ops", lambda path, n: scopes.program_ops(planes, n))
+    monkeypatch.setattr(scopes, "live_op_names", lambda: names)
+    ctx = {"trace": tracereduce.reduce_planes(planes, n_chips)}
+    return harness.reader("reassembly_scans_ms").read(ctx)
+
+
+GROUPED = dict(NAMES, bench_reassembly=dict(
+    NAMES["bench_reassembly"],
+    **{"fusion.2": "jit(bench_reassembly)/jit(reassembly_plan)/reassembly.groups/cumsum"}))
+
+
+def test_scans_are_the_program_less_sort_and_groups(monkeypatch):
+    # bench_reassembly runs 10 + 14 ms on chip 0 and 15 + 7 ms on chip 1:
+    # 11.5 ms per call; sort 3 ms and groups (fusion.2) 1 ms per call
+    assert scans(monkeypatch, GROUPED) == pytest.approx(11.5 - 3 - 1)
+
+
+def test_scans_without_a_scope_read_none(monkeypatch):
+    assert scans(monkeypatch, NAMES) is None  # no reassembly.groups
+    assert scans(monkeypatch, {}) is None  # the program's HLO not held
+
+
+def test_scans_of_a_route_trace_read_none(tmp_path, monkeypatch):
+    shutil.copy(RECORDED, tmp_path / "route.xplane.pb")
+    monkeypatch.setattr(scopes, "TRACES", str(tmp_path))
+    ctx = {"trace": tracereduce.reduce(RECORDED, 1)}
+    assert harness.reader("reassembly_scans_ms").read(ctx) is None
 
 
 def test_live_executables_carry_the_scopes():
